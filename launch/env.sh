@@ -6,6 +6,7 @@
 # Forces NDEVICES host CPU devices (XLA reads the flag once at backend init)
 # and preloads tcmalloc when available — large-grid benchmarks allocate and
 # free multi-GB halo-extended slabs per wave, where glibc malloc fragments.
+# CPU-only: it pins JAX_PLATFORMS=cpu, so never source it for a chip run.
 # Python-side equivalent: repro.launch.hostenv.
 N="${1:-8}"
 export XLA_FLAGS="--xla_force_host_platform_device_count=${N}"

@@ -41,6 +41,9 @@ class Result:
     dice_after: Optional[Any] = None
     # slab-distributed solves: mesh axis -> size (None for single-device)
     mesh: Optional[Dict[str, int]] = None
+    # per-Newton-evaluation records of the core driver (gnorm, rel_grad,
+    # pcg_iters, ...); left out of to_dict()
+    history: Optional[list] = None
 
     def to_dict(self) -> Dict:
         """JSON-serializable summary (arrays and per-iteration logs omitted)."""

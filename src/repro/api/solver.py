@@ -31,6 +31,7 @@ def _build_result(mode: str, problem: RegistrationProblem, res,
         mismatch_rel=res.mismatch_rel, detF=res.detF,
         iters=res.iters, matvecs=res.matvecs, rel_grad=res.rel_grad,
         converged=res.converged, wall_time_s=res.wall_time_s, mesh=mesh,
+        history=res.history,
     )
     if mode == "batch":
         return Result(batch=problem.batch_size, **common)

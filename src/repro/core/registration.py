@@ -96,13 +96,25 @@ def make_transport_config(
     for benchmarking and regression tests). ``measure`` selects the distance
     measure (``"ssd" | "ncc" | "ngf"`` or a ``measures.DistanceMeasure``
     instance). ``use_fused_matvec`` routes the PCG Hessian matvec through
-    the fused gather+epilogue Pallas kernel (requires ``use_plan``)."""
+    the fused gather+epilogue Pallas kernel (requires ``use_plan``).
+
+    On the TPU, ``backend="pallas"`` and ``use_fused_matvec=True`` raise
+    ``NotImplementedError``: both interpolate through the Pallas gather
+    kernels of ``kernels.interp3d``, which Mosaic cannot compile."""
     if variant not in VARIANTS:
         raise ValueError(f"unknown variant {variant!r}; choose from {sorted(VARIANTS)}")
     _meas.resolve(measure)  # fail fast on unknown measure names
     if use_fused_matvec and not use_plan:
         raise ValueError("use_fused_matvec requires use_plan=True (the fused "
                          "kernel consumes prebuilt interpolation plans)")
+    if (backend == "pallas" or use_fused_matvec) and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "backend='pallas' and use_fused_matvec=True interpolate through "
+            "the Pallas gather kernels of repro.kernels.interp3d, which Mosaic "
+            "refuses for the TPU: 'Only 2D gather is supported' for the flat "
+            "jnp.take of apply_plan_pallas/apply_plan_fused, and a halo block "
+            "whose last two dims are not multiples of (8, 128) in "
+            "interp3d_pallas. Use backend='jnp' and use_fused_matvec=False.")
     sel = VARIANTS[variant]
     return _tr.TransportConfig(
         interp=sel["interp"],
@@ -404,10 +416,13 @@ def register_sharded(
     Pallas kernel's ``PALLAS_DISPLACEMENT_BOUND``. The solver regime
     (``|v| dt / h`` of a few voxels) satisfies the default; raise ``halo``
     for aggressive velocities. Post-solve metrics are computed on the
-    gathered velocity.
+    gathered velocity. A mesh with ``Explicit`` axes (the default of
+    ``jax.make_mesh``) is converted to ``Auto`` axes on the same devices.
     """
     from repro.distributed import claire_dist as _dist
+    from repro.launch.mesh import auto_axes
 
+    mesh = auto_axes(mesh)
     cfg = make_transport_config(variant, nt=nt, backend=backend,
                                 mixed_precision=mixed_precision,
                                 use_plan=use_plan, measure=measure,

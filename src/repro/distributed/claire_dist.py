@@ -38,7 +38,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import gauss_newton as _gn
 from repro.core import gradient as _grad
@@ -151,11 +150,11 @@ def halo_sl_step(mesh: Mesh, method: str = "cubic_bspline",
         plan = _halo.build_plan(foot_loc, method, None, shard)
         return _halo.apply_plan(plan, f_loc, method, shard)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None, None), P(None, axis, None, None)),
         out_specs=P(axis, None, None),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -241,9 +240,9 @@ def make_slab_step(mesh: Mesh, cfg: _tr.TransportConfig, gn: _gn.GNConfig,
     out_specs = _gn.NewtonStepStats(
         v_new=vel, gnorm=stat, j_total=stat, j_mismatch=stat, j_reg=stat,
         pcg_iters=stat, pcg_residual=stat, alpha=stat, ls_evals=stat)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(img, img, vel, P(), P(), eta_spec),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(img, img, vel, P(), P(), eta_spec),
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 

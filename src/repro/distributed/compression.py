@@ -21,7 +21,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def quantize_int8(g: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -40,9 +39,7 @@ def compressed_psum_pod(grads: Any, axis: str = "pod") -> Any:
 
     Must run inside a shard_map manual over ``axis``.
     """
-    # jax.lax.axis_size is not in JAX 0.4.x; psum of a literal 1 is folded to
-    # the axis size at trace time (no collective is emitted).
-    n = jax.lax.psum(1, axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g):
         q, scale = quantize_int8(g)
@@ -65,8 +62,6 @@ def make_compressed_grad_fn(loss_fn, mesh):
         # single pod: plain value_and_grad
         return jax.value_and_grad(loss_fn, has_aux=True)
 
-    auto = frozenset(a for a in mesh.axis_names if a != "pod")
-
     def local_grad(params, batch):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, batch)
@@ -75,12 +70,12 @@ def make_compressed_grad_fn(loss_fn, mesh):
         aux = jax.tree.map(lambda a: jax.lax.pmean(a, "pod"), aux)
         return (loss, aux), grads
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_grad, mesh=mesh,
         in_specs=(P(), P("pod")),      # params replicated, batch pod-sharded
         out_specs=((P(), P()), P()),
-        check_rep=False,
-        auto=auto,
+        axis_names={"pod"},
+        check_vma=False,
     )
 
     def wrapped(params, batch):

@@ -21,10 +21,12 @@ Weights: trilinear (2 taps/axis) or cubic (4 taps/axis, B-spline or Lagrange
 — the basis only changes the weight polynomials; for B-spline the input must
 be prefiltered coefficients, see ``repro.kernels.prefilter``).
 
-The in-VMEM gather is expressed with ``jnp.take``; it is validated in
-interpret mode here (CPU container). On real hardware this lowers to Mosaic
-dynamic-gather; the pure-XLA fallback (``repro.core.interp``) remains the
-default path of the distributed solver.
+The in-VMEM gather is expressed with a flat ``jnp.take``, which runs in
+interpret mode only: Mosaic refuses it for the TPU ("Only 2D gather is
+supported"), and it refuses ``interp3d_pallas``'s halo block because its last
+two dims are not multiples of (8, 128). The XLA path (``repro.core.interp``)
+is the default on every platform; ``core.registration.make_transport_config``
+rejects the kernels on the TPU.
 """
 
 from __future__ import annotations
@@ -81,33 +83,26 @@ _BASES = {
 
 
 def _interp_body(q1_ref, q2_ref, q3_ref, fpad_ref, o_ref, *,
-                 basis, halo, block, weight_dtype, full_field=False):
+                 basis, halo, block, weight_dtype):
     """One output tile: gather + tensor-product basis evaluation."""
     weight_fn, support, base_off = _BASES[basis]
     b1, b2, b3 = block
     h = halo
 
-    tile = fpad_ref[...]  # (b1+2h, b2+2h, b3+2h) in VMEM (or full field)
+    tile = fpad_ref[...]  # (b1+2h, b2+2h, b3+2h) in VMEM
     t1, t2, t3 = tile.shape
     # Mixed precision is weights-only (paper's scheme): the field keeps its
     # native precision, weights are downcast below, accumulation is fp32.
     tile_flat = tile.reshape(-1)
 
-    if full_field:
-        # Compat path (no pl.Element): the ref holds the whole padded field,
-        # so queries address it directly in the global padded frame.
-        l1 = q1_ref[...] + h
-        l2 = q2_ref[...] + h
-        l3 = q3_ref[...] + h
-    else:
-        i = pl.program_id(0)
-        j = pl.program_id(1)
-        k = pl.program_id(2)
-        # Local (tile-frame) query coordinates. Global padded coordinate of a
-        # query q is q + h; this tile starts at element offset (i*b1, j*b2, k*b3).
-        l1 = q1_ref[...] + (h - i * b1)
-        l2 = q2_ref[...] + (h - j * b2)
-        l3 = q3_ref[...] + (h - k * b3)
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    k = pl.program_id(2)
+    # Local (tile-frame) query coordinates. Global padded coordinate of a
+    # query q is q + h; this tile starts at element offset (i*b1, j*b2, k*b3).
+    l1 = q1_ref[...] + (h - i * b1)
+    l2 = q2_ref[...] + (h - j * b2)
+    l3 = q3_ref[...] + (h - k * b3)
 
     f1 = jnp.floor(l1)
     f2 = jnp.floor(l2)
@@ -178,22 +173,14 @@ def interp3d_pallas(
     fpad = jnp.pad(f, halo, mode="wrap")
 
     q_spec = pl.BlockSpec((b1, b2, b3), lambda i, j, k: (i, j, k))
-    full_field = not hasattr(pl, "Element")
-    if full_field:
-        # Pallas in JAX 0.4.x has no element-indexed BlockSpec, so overlapping
-        # halo tiles cannot be expressed: hand every program the whole padded
-        # field as block 0 and let the body index it globally. Correctness is
-        # identical; on real hardware the Element path is the fast one.
-        f_spec = pl.BlockSpec(fpad.shape, lambda i, j, k: (0, 0, 0))
-    else:
-        # Overlapping halo tiles: element-indexed BlockSpec with stride = block.
-        f_spec = pl.BlockSpec(
-            (pl.Element(b1 + 2 * halo), pl.Element(b2 + 2 * halo), pl.Element(b3 + 2 * halo)),
-            lambda i, j, k: (i * b1, j * b2, k * b3),
-        )
+    # Overlapping halo tiles: element-indexed BlockSpec with stride = block.
+    f_spec = pl.BlockSpec(
+        (pl.Element(b1 + 2 * halo), pl.Element(b2 + 2 * halo), pl.Element(b3 + 2 * halo)),
+        lambda i, j, k: (i * b1, j * b2, k * b3),
+    )
     body = functools.partial(
         _interp_body, basis=basis, halo=halo, block=block,
-        weight_dtype=weight_dtype, full_field=full_field,
+        weight_dtype=weight_dtype,
     )
     return pl.pallas_call(
         body,
@@ -253,10 +240,9 @@ def apply_plan_pallas(
     """Evaluate coefficients ``coef`` through an ``InterpPlan`` (Pallas path).
 
     The whole (unpadded) field is handed to each program as a VMEM block and
-    gathered with the plan's global flat indices (the JAX 0.4.x fallback
-    BlockSpec layout, matching ``interp3d_pallas``); output, index and weight
-    arrays are tiled. An ``pl.Element``-tiled variant (plan indices rebased to
-    the tile frame) is the fast path on hardware that supports it.
+    gathered with the plan's global flat indices; output, index and weight
+    arrays are tiled. Mosaic refuses the flat 1D ``jnp.take`` ("Only 2D
+    gather is supported"), so this kernel runs in interpret mode only.
     """
     support = plan.support
     if tuple(coef.shape[-3:]) != plan.field_shape:

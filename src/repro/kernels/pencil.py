@@ -11,8 +11,9 @@ the 15-point B-spline prefilter) follow the same TPU-native pattern:
   * grid iteration streams pencils HBM -> VMEM -> HBM exactly once, which is
     the memory-bound optimum the paper's roofline analysis targets.
 
-On non-TPU backends (this container) kernels run with ``interpret=True``,
-which executes the same block program in Python for correctness validation.
+On the CPU kernels run with ``interpret=True``, which executes the same
+block program in Python for correctness validation; on the TPU they compile
+with Mosaic. No other platform is supported.
 """
 
 from __future__ import annotations
@@ -26,8 +27,13 @@ from jax.experimental import pallas as pl
 
 
 def interpret_default() -> bool:
-    """Pallas interpret mode unless we are actually on TPU."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU, compiled kernels on the TPU."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise NotImplementedError(
+            f"Pallas kernels here run on 'tpu' (compiled) or 'cpu' "
+            f"(interpret mode), not on {platform!r}")
+    return platform == "cpu"
 
 
 def largest_divisor(n: int, target: int) -> int:

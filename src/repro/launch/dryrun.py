@@ -107,8 +107,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str) -> dict:
     # DESIGN.md) — useless under scan-over-layers. We walk the partitioned
     # HLO with trip-count weighting instead; raw values kept for reference.
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # JAX 0.4.x returns [dict]; 0.5+ a dict
-        ca = ca[0] if ca else {}
     rec["xla_cost_analysis"] = dict(
         flops=float(ca.get("flops", 0.0)),
         bytes_accessed=float(ca.get("bytes accessed", 0.0)),

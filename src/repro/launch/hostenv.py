@@ -1,4 +1,4 @@
-"""Host-platform launch environment helpers.
+"""Host-platform launch environment helpers. CPU-only tools.
 
 Benchmarks and multi-device tests on machines without accelerators force a
 multi-device view of the host CPU (``--xla_force_host_platform_device_count``).
@@ -8,6 +8,11 @@ implementation of that trick — plus the optional tcmalloc preload that
 stabilizes large-grid host allocations — so the dist/roofline bench modes and
 the launch scripts stop rolling their own re-exec logic. ``launch/env.sh`` is
 the shell-side equivalent for interactive runs.
+
+Nothing here runs on an accelerator: the parent has already taken the chip
+when it counts devices, so a child could not reach it, and a child pinned to
+the CPU would hide the device. On a TPU host, :func:`ensure_host_devices`
+raises instead of re-executing; ``chip_smoke.py`` never calls this module.
 """
 
 from __future__ import annotations
@@ -56,16 +61,23 @@ def ensure_host_devices(devices: int, argv: Optional[Sequence[str]] = None,
     """Ensure this process sees at least ``devices`` JAX devices.
 
     Returns False when the requirement already holds (caller proceeds
-    normally). Otherwise re-runs ``argv`` (default: ``sys.argv`` under the
-    current interpreter) in a child carrying the forced-host-device
-    environment and returns True — the caller should return immediately. A
-    sentinel guards against a re-exec loop: a child that still sees too few
-    devices aborts instead of forking forever.
+    normally). On the CPU backend it otherwise re-runs ``argv`` (default:
+    ``sys.argv`` under the current interpreter) in a child carrying the
+    forced-host-device environment and returns True — the caller should
+    return immediately. A sentinel guards against a re-exec loop: a child
+    that still sees too few devices aborts instead of forking forever. On any
+    other backend too few devices is an error (``RuntimeError``).
     """
     import jax
 
     if jax.device_count() >= devices:
         return False
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"[launch] need {devices} devices but the {platform!r} backend "
+            f"has {jax.device_count()}; forced host devices are a CPU-only "
+            "fallback")
     if os.environ.get(_SENTINEL):
         raise SystemExit(
             f"[launch] forced {devices} host devices but jax reports "

@@ -11,6 +11,12 @@ jax initialization.
 The ``pod`` axis is pure data parallelism across the DCN boundary; ``data``
 is intra-pod data parallelism; ``model`` carries TP / expert / sequence /
 grid-slab sharding depending on workload.
+
+Every mesh built here has ``AxisType.Auto`` axes: the solver places its
+arrays with ``NamedSharding``/``shard_map`` and lets GSPMD propagate
+shardings through the unsharded glue code (restrict/prolong, scoring).
+``jax.make_mesh`` defaults to ``Explicit`` axes, under which that glue code
+raises ``ShardingTypeError``; :func:`auto_axes` converts such a mesh.
 """
 
 from __future__ import annotations
@@ -18,21 +24,27 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-
-
-# JAX 0.4.x: jax.make_mesh has no axis_types parameter (all axes behave as
-# the later AxisType.Auto); it arrived with jax.sharding.AxisType in 0.5+.
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Arbitrary mesh (smoke tests use (1, 1) or (2, 2) host meshes)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``AxisType.Auto`` (same devices and names)."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def dp_axis_names(mesh) -> Tuple[str, ...]:

@@ -18,6 +18,7 @@ import jax
 from repro.configs import REGISTRATIONS, get_registration
 from repro.core.registration import VARIANTS, register
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -34,6 +35,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.grid:
         grid = (args.grid,) * 3

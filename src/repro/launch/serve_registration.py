@@ -125,7 +125,10 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import ServeConfig, Server
+
+    enable_compile_cache()
 
     if args.smoke:
         grid_sizes = [int(g) for g in (args.grids or "12,16").split(",")]

@@ -35,10 +35,7 @@ def test_halo_sl_step_matches_single_device(forced_devices):
         cfg = T.TransportConfig(interp="cubic_bspline", nt=4)
         foot = T.footpoints(pair.v_true, cfg)
         ref = SL.sl_step(pair.m0, foot, cfg.interp)
-        # jax.set_mesh is 0.5+; shard_map carries the mesh explicitly and the
-        # 0.4.x Mesh context manager covers the ambient-mesh uses.
-        with mesh:
-            sharded = jax.jit(halo_sl_step(mesh, halo=8))(pair.m0, foot)
+        sharded = jax.jit(halo_sl_step(mesh, halo=8))(pair.m0, foot)
         # the halo prefilter is exact -> only fp32 op-ordering noise remains
         np.testing.assert_allclose(np.asarray(sharded), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -51,16 +48,16 @@ def test_compressed_psum_matches_mean(forced_devices):
     forced_devices(4, """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.launch.mesh import make_mesh
         from repro.distributed.compression import compressed_psum_pod
 
         mesh = make_mesh((4,), ("pod",))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
-        f = shard_map(lambda x: compressed_psum_pod({"g": x[0]}, "pod")["g"],
-                      mesh=mesh, in_specs=(P("pod", None),),
-                      out_specs=P(None), check_rep=False)
+        f = jax.shard_map(
+            lambda x: compressed_psum_pod({"g": x[0]}, "pod")["g"],
+            mesh=mesh, in_specs=(P("pod", None),), out_specs=P(None),
+            check_vma=False)
         # out_specs P(None): identical replicas -> take as-is
         approx = f(g.reshape(4, 1, 64))
         exact = jnp.mean(g, axis=0)

@@ -88,8 +88,6 @@ def test_live_scan_flops_weighted():
     assert c.flops == pytest.approx(11 * 2 * 4 * 32 * 32)
     # sanity: cost_analysis (unweighted) reports only ~1 body
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # JAX 0.4.x returns [dict]; 0.5+ a dict
-        ca = ca[0]
     assert ca["flops"] < c.flops / 5
 
 

@@ -12,10 +12,8 @@ from repro.configs import ARCHS
 from repro.distributed import sharding as shd
 from repro.models import build_model
 
-# JAX 0.4.x API: AbstractMesh takes a ((name, size), ...) shape tuple and
-# has no AxisType (all axes behave as Auto); axis_types arrived in 0.5+.
-MESH_1POD = AbstractMesh((("data", 16), ("model", 16)))
-MESH_2POD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_sizes(mesh):
