@@ -11,6 +11,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 from typing import Optional
 
+from repro import obs
 from repro.core import metrics as _metrics
 from repro.core import registration as _reg
 
@@ -50,8 +51,15 @@ class Solver:
         mode = o.resolve_mode(problem.is_batched, problem.grid)
         if mode == "batch" and o.continuation:
             raise ValueError("continuation is not supported with batched solving")
-        if o.mesh is not None:
-            return self._solve_sharded(problem, mode)
+        with obs.span(obs.SOLVE, mode=mode, grid=tuple(problem.grid),
+                      batch=problem.batch_size or 1,
+                      sharded=o.mesh is not None):
+            if o.mesh is not None:
+                return self._solve_sharded(problem, mode)
+            return self._solve(problem, mode)
+
+    def _solve(self, problem: RegistrationProblem, mode: str) -> Result:
+        o = self.options
         common = dict(
             variant=o.variant, beta=o.beta, gamma=o.gamma, nt=o.nt,
             tol_rel_grad=o.tol_rel_grad, max_newton=o.max_newton,
@@ -110,23 +118,24 @@ class Solver:
     def _with_dice(self, problem: RegistrationProblem, result: Result) -> Result:
         if problem.labels0 is None or problem.labels1 is None:
             return result
-        cfg = _reg.make_transport_config(
-            self.options.variant, nt=self.options.nt,
-            backend=self.options.backend,
-            mixed_precision=self.options.mixed_precision,
-        )
-        if problem.is_batched:
-            before, after = [], []
-            for b in range(problem.batch_size):
-                before.append(float(_metrics.dice(problem.labels0[b],
-                                                  problem.labels1[b])))
-                warped = _metrics.warp_labels(problem.labels0[b], result.v[b], cfg)
-                after.append(float(_metrics.dice(warped, problem.labels1[b])))
-        else:
-            before = float(_metrics.dice(problem.labels0, problem.labels1))
-            warped = _metrics.warp_labels(problem.labels0, result.v, cfg)
-            after = float(_metrics.dice(warped, problem.labels1))
-        return replace(result, dice_before=before, dice_after=after)
+        with obs.span(obs.DICE):
+            cfg = _reg.make_transport_config(
+                self.options.variant, nt=self.options.nt,
+                backend=self.options.backend,
+                mixed_precision=self.options.mixed_precision,
+            )
+            if problem.is_batched:
+                before, after = [], []
+                for b in range(problem.batch_size):
+                    before.append(float(_metrics.dice(problem.labels0[b],
+                                                      problem.labels1[b])))
+                    warped = _metrics.warp_labels(problem.labels0[b], result.v[b], cfg)
+                    after.append(float(_metrics.dice(warped, problem.labels1[b])))
+            else:
+                before = float(_metrics.dice(problem.labels0, problem.labels1))
+                warped = _metrics.warp_labels(problem.labels0, result.v, cfg)
+                after = float(_metrics.dice(warped, problem.labels1))
+            return replace(result, dice_before=before, dice_after=after)
 
 
 def solve(problem: RegistrationProblem,

@@ -20,6 +20,8 @@ from typing import Literal, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import grid as _grid
 
 # 8th-order central-difference coefficients for the first derivative:
@@ -37,6 +39,7 @@ def _fd8_axis_jnp(f: jnp.ndarray, axis: int, h: float) -> jnp.ndarray:
     return out / h
 
 
+@obs.scoped(obs.FD8)
 def fd8_partial(f: jnp.ndarray, axis: int, backend: Backend = "jnp") -> jnp.ndarray:
     """Partial derivative of a scalar field along ``axis`` (0, 1 or 2)."""
     h = _grid.spacing(f.shape)[axis]
@@ -47,6 +50,7 @@ def fd8_partial(f: jnp.ndarray, axis: int, backend: Backend = "jnp") -> jnp.ndar
     return _fd8_axis_jnp(f, axis, h)
 
 
+@obs.scoped(obs.FD8)
 def fd8_grad(f: jnp.ndarray, backend: Backend = "jnp") -> jnp.ndarray:
     """Gradient of a scalar field, output shape (3, N1, N2, N3)."""
     if backend == "pallas":
@@ -56,6 +60,7 @@ def fd8_grad(f: jnp.ndarray, backend: Backend = "jnp") -> jnp.ndarray:
     return jnp.stack([fd8_partial(f, a) for a in range(3)], axis=0)
 
 
+@obs.scoped(obs.FD8)
 def fd8_div(w: jnp.ndarray, backend: Backend = "jnp") -> jnp.ndarray:
     """Divergence of a vector field (3, N1, N2, N3) -> (N1, N2, N3)."""
     if backend == "pallas":
@@ -116,7 +121,8 @@ def grad(f: jnp.ndarray, scheme: str = "fd8", backend: Backend = "jnp",
         from repro.distributed import halo as _halo
 
         if scheme == "fd8":
-            return _halo.fd8_grad(f, shard)
+            with jax.named_scope(obs.FD8):
+                return _halo.fd8_grad(f, shard)
         if scheme == "fft":
             return _halo.spectral_grad(f, shard)
         raise ValueError(f"unknown derivative scheme: {scheme}")
@@ -133,7 +139,8 @@ def div(w: jnp.ndarray, scheme: str = "fd8", backend: Backend = "jnp",
         from repro.distributed import halo as _halo
 
         if scheme == "fd8":
-            return _halo.fd8_div(w, shard)
+            with jax.named_scope(obs.FD8):
+                return _halo.fd8_div(w, shard)
         if scheme == "fft":
             return _halo.spectral_div(w, shard)
         raise ValueError(f"unknown derivative scheme: {scheme}")

@@ -13,13 +13,14 @@ scalars).
 
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Any, Callable, Dict, List, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
 
 from . import gradient as _grad
 from . import grid as _grid
@@ -77,6 +78,7 @@ def _build_step(cfg: _tr.TransportConfig, gn: GNConfig):
         j0 = gs.j_mismatch + gs.j_reg
         gdotp = _grid.inner(gs.g, vt, shard=cfg.shard)
 
+        @obs.scoped(obs.LINESEARCH)
         def trial_obj(a):
             # The trial velocity moves the footpoints, so the Newton-step
             # plans cannot be reused here; solve_state still builds one plan
@@ -116,11 +118,19 @@ def _build_step(cfg: _tr.TransportConfig, gn: GNConfig):
     return step
 
 
+@obs.span(obs.BUILD)
 def _make_step(cfg: _tr.TransportConfig, gn: GNConfig):
     """Jitted Newton step for one image pair."""
-    return jax.jit(_build_step(cfg, gn))
+    body = _build_step(cfg, gn)
+
+    def step(m0, m1, v, beta, gamma, eta):
+        obs.count_trace("newton_step")
+        return body(m0, m1, v, beta, gamma, eta)
+
+    return jax.jit(step)
 
 
+@obs.span(obs.BUILD)
 def _make_batch_step(cfg: _tr.TransportConfig, gn: GNConfig,
                      donate: bool = False):
     """Jitted Newton step vmapped over a leading batch axis.
@@ -144,9 +154,14 @@ def _make_batch_step(cfg: _tr.TransportConfig, gn: GNConfig,
     """
     vstep = jax.vmap(_build_step(cfg, gn), in_axes=(0, 0, 0, None, None, 0))
     if not donate:
-        return jax.jit(vstep)
+        def step(m0, m1, v, beta, gamma, eta):
+            obs.count_trace("newton_step_batch")
+            return vstep(m0, m1, v, beta, gamma, eta)
+
+        return jax.jit(step)
 
     def step(m0, m1, v, beta, gamma, eta, gnorm_ref, active):
+        obs.count_trace("newton_step_batch")
         stats = vstep(m0, m1, v, beta, gamma, eta)
         use_ref = jnp.isfinite(gnorm_ref) & (gnorm_ref > 0)
         gnorm0 = jnp.where(use_ref, gnorm_ref, stats.gnorm)
@@ -159,6 +174,10 @@ def _make_batch_step(cfg: _tr.TransportConfig, gn: GNConfig,
 
 
 class GNResult(NamedTuple):
+    """``wall_time_s``: seconds from the start of the first Newton
+    evaluation to the end of the last (their ``claire.newton`` spans),
+    tracing and compiling the step included."""
+
     v: jnp.ndarray
     iters: int
     matvecs: int
@@ -222,7 +241,7 @@ def solve(
     total_iters = 0
     gnorm0_global = gnorm_ref
     gnorm_last = None
-    t0 = time.perf_counter()
+    first = last = None
 
     for level, beta in enumerate(betas):
         is_target = level == len(betas) - 1
@@ -233,55 +252,55 @@ def solve(
         gnorm0_level = gnorm_ref
         prev_gnorm = None
         for _ in range(max(budget, 1)):
-            # Eisenstat-Walker superlinear forcing: eta = min(cap, sqrt(g/g0)).
-            if gnorm0_level is None or prev_gnorm is None:
-                eta = min(gn.forcing_max, eta0) if eta0 is not None else gn.forcing_max
-            else:
-                eta = float(
-                    min(gn.forcing_max, (prev_gnorm / gnorm0_level) ** 0.5)
-                )
-            stats = step_fn(m0, m1, v, jnp.float32(beta), jnp.float32(gn.gamma), jnp.float32(eta))
-            gnorm = float(stats.gnorm)
-            if gnorm0_level is None:
-                gnorm0_level = gnorm
-            if gnorm0_global is None:
-                gnorm0_global = gnorm
-            rel = gnorm / gnorm0_level if gnorm0_level > 0 else 0.0
-            history.append(
-                dict(
-                    level=level,
-                    beta=beta,
-                    gnorm=gnorm,
-                    rel_grad=rel,
-                    j=float(stats.j_total),
-                    j_mismatch=float(stats.j_mismatch),
-                    j_reg=float(stats.j_reg),
-                    pcg_iters=int(stats.pcg_iters),
-                    alpha=float(stats.alpha),
-                    ls_evals=int(stats.ls_evals),
-                )
-            )
-            if verbose:
-                h = history[-1]
-                print(
-                    f"[GN] lvl={level} beta={beta:.1e} it={total_iters:3d} "
-                    f"J={h['j']:.4e} mis={h['j_mismatch']:.4e} |g|rel={rel:.3e} "
-                    f"pcg={h['pcg_iters']} a={h['alpha']:.3f}"
-                )
-            gnorm_last = gnorm
-            # The step's PCG solve ran whether or not we accept the update,
-            # so its matvecs count toward the Table-1 work accounting even on
-            # the final (converged) step.
-            total_matvecs += int(stats.pcg_iters)
-            if rel <= tol:
-                # converged at this level -- do not apply the (already
-                # computed) step past the tolerance; keep v as-is.
-                break
-            v = stats.v_new
-            prev_gnorm = gnorm
-            total_iters += 1
-            if total_iters >= gn.max_newton:
-                break
+            with obs.span(obs.NEWTON, step_num=len(history)) as last:
+                first = first or last
+                # Eisenstat-Walker superlinear forcing: eta = min(cap, sqrt(g/g0)).
+                if gnorm0_level is None or prev_gnorm is None:
+                    eta = min(gn.forcing_max, eta0) if eta0 is not None else gn.forcing_max
+                else:
+                    eta = float(
+                        min(gn.forcing_max, (prev_gnorm / gnorm0_level) ** 0.5)
+                    )
+                with obs.span(obs.DISPATCH):
+                    stats = step_fn(m0, m1, v, jnp.float32(beta),
+                                    jnp.float32(gn.gamma), jnp.float32(eta))
+                with obs.span(obs.SYNC):
+                    gnorm = float(stats.gnorm)
+                    h = dict(
+                        j=float(stats.j_total),
+                        j_mismatch=float(stats.j_mismatch),
+                        j_reg=float(stats.j_reg),
+                        pcg_iters=int(stats.pcg_iters),
+                        alpha=float(stats.alpha),
+                        ls_evals=int(stats.ls_evals),
+                    )
+                if gnorm0_level is None:
+                    gnorm0_level = gnorm
+                if gnorm0_global is None:
+                    gnorm0_global = gnorm
+                rel = gnorm / gnorm0_level if gnorm0_level > 0 else 0.0
+                history.append(dict(level=level, beta=beta, gnorm=gnorm,
+                                    rel_grad=rel, **h))
+                if verbose:
+                    print(
+                        f"[GN] lvl={level} beta={beta:.1e} it={total_iters:3d} "
+                        f"J={h['j']:.4e} mis={h['j_mismatch']:.4e} |g|rel={rel:.3e} "
+                        f"pcg={h['pcg_iters']} a={h['alpha']:.3f}"
+                    )
+                gnorm_last = gnorm
+                # The step's PCG solve ran whether or not we accept the update,
+                # so its matvecs count toward the Table-1 work accounting even on
+                # the final (converged) step.
+                total_matvecs += h["pcg_iters"]
+                if rel <= tol:
+                    # converged at this level -- do not apply the (already
+                    # computed) step past the tolerance; keep v as-is.
+                    break
+                v = stats.v_new
+                prev_gnorm = gnorm
+                total_iters += 1
+                if total_iters >= gn.max_newton:
+                    break
         if total_iters >= gn.max_newton:
             break
 
@@ -297,7 +316,7 @@ def solve(
         rel_grad=rel_final,
         converged=rel_final <= gn.tol_rel_grad,
         history=history,
-        wall_time_s=time.perf_counter() - t0,
+        wall_time_s=obs.elapsed_s(first, last),
     )
 
 
@@ -316,7 +335,7 @@ class BatchGNResult(NamedTuple):
     rel_grad: np.ndarray      # (B,)
     converged: np.ndarray     # (B,) bool
     history: List[Dict[str, np.ndarray]]   # per evaluation, per-pair arrays
-    wall_time_s: float
+    wall_time_s: float        # as GNResult's: Newton spans, compiling included
 
 
 def solve_batch(
@@ -375,84 +394,88 @@ def solve_batch(
     gnorm_last = np.zeros(bsz, dtype=np.float64)
     eta = np.full(bsz, gn.forcing_max, dtype=np.float64)
     history: List[Dict[str, np.ndarray]] = []
-    t0 = time.perf_counter()
+    first = last = None
 
     for _ in range(gn.max_newton):
-        if donate:
-            # First step: pass the caller's reference (NaN where absent) and
-            # let the device fall back to the observed gnorm — the same
-            # resolution the host bookkeeping below applies to gnorm0.
-            if gnorm0 is not None:
-                ref_arg = gnorm0
-            elif gnorm_ref is not None:
-                ref_arg = np.broadcast_to(
-                    np.asarray(gnorm_ref, dtype=np.float64), (bsz,))
+        with obs.span(obs.NEWTON, step_num=len(history)) as last:
+            first = first or last
+            with obs.span(obs.DISPATCH):
+                if donate:
+                    # First step: pass the caller's reference (NaN where
+                    # absent) and let the device fall back to the observed
+                    # gnorm — the same resolution the host bookkeeping below
+                    # applies to gnorm0.
+                    if gnorm0 is not None:
+                        ref_arg = gnorm0
+                    elif gnorm_ref is not None:
+                        ref_arg = np.broadcast_to(
+                            np.asarray(gnorm_ref, dtype=np.float64), (bsz,))
+                    else:
+                        ref_arg = np.full(bsz, np.nan)
+                    stats, adv_dev = bstep(
+                        m0, m1, v,
+                        jnp.float32(gn.beta), jnp.float32(gn.gamma),
+                        jnp.asarray(eta, dtype=jnp.float32),
+                        jnp.asarray(ref_arg, dtype=jnp.float32),
+                        jnp.asarray(active),
+                    )
+                else:
+                    stats = bstep(
+                        m0, m1, v,
+                        jnp.float32(gn.beta), jnp.float32(gn.gamma),
+                        jnp.asarray(eta, dtype=jnp.float32),
+                    )
+            with obs.span(obs.SYNC):
+                gnorm = np.asarray(stats.gnorm, dtype=np.float64)
+                pcg = np.asarray(stats.pcg_iters, dtype=np.int64)
+                adv = np.asarray(adv_dev, dtype=bool) if donate else None
+                h = dict(
+                    j=np.asarray(stats.j_total, dtype=np.float64),
+                    j_mismatch=np.asarray(stats.j_mismatch, dtype=np.float64),
+                    pcg_iters=pcg,
+                    alpha=np.asarray(stats.alpha, dtype=np.float64),
+                    ls_evals=np.asarray(stats.ls_evals, dtype=np.int64),
+                )
+            if gnorm0 is None:
+                gnorm0 = gnorm.copy()
+                if gnorm_ref is not None:
+                    ref = np.broadcast_to(
+                        np.asarray(gnorm_ref, dtype=np.float64), (bsz,)).copy()
+                    use_ref = np.isfinite(ref) & (ref > 0)
+                    gnorm0 = np.where(use_ref, ref, gnorm0)
+            rel = np.where(gnorm0 > 0, gnorm / gnorm0, 0.0)
+            gnorm_last = np.where(active, gnorm, gnorm_last)
+            # Final-step PCG work counts, matching the unbatched accounting.
+            matvecs += np.where(active, pcg, 0)
+            if donate:
+                # The device already applied the freeze mask to v_new; mirror
+                # its decision so host bookkeeping and the update cannot
+                # diverge.
+                advance = adv & active
+                just_conv = active & ~advance
+                v = stats.v_new
             else:
-                ref_arg = np.full(bsz, np.nan)
-            stats, adv_dev = bstep(
-                m0, m1, v,
-                jnp.float32(gn.beta), jnp.float32(gn.gamma),
-                jnp.asarray(eta, dtype=jnp.float32),
-                jnp.asarray(ref_arg, dtype=jnp.float32),
-                jnp.asarray(active),
+                just_conv = active & (rel <= gn.tol_rel_grad)
+                advance = active & ~just_conv
+                mask = jnp.asarray(advance).reshape((bsz,) + (1,) * (v.ndim - 1))
+                v = jnp.where(mask, stats.v_new, v)
+            ever_converged |= just_conv
+            iters += advance
+            eta = np.where(
+                advance,
+                np.minimum(gn.forcing_max,
+                           np.sqrt(np.maximum(gnorm, 0.0) / np.maximum(gnorm0, 1e-30))),
+                eta,
             )
-        else:
-            stats = bstep(
-                m0, m1, v,
-                jnp.float32(gn.beta), jnp.float32(gn.gamma),
-                jnp.asarray(eta, dtype=jnp.float32),
-            )
-        gnorm = np.asarray(stats.gnorm, dtype=np.float64)
-        if gnorm0 is None:
-            gnorm0 = gnorm.copy()
-            if gnorm_ref is not None:
-                ref = np.broadcast_to(
-                    np.asarray(gnorm_ref, dtype=np.float64), (bsz,)).copy()
-                use_ref = np.isfinite(ref) & (ref > 0)
-                gnorm0 = np.where(use_ref, ref, gnorm0)
-        rel = np.where(gnorm0 > 0, gnorm / gnorm0, 0.0)
-        gnorm_last = np.where(active, gnorm, gnorm_last)
-        pcg = np.asarray(stats.pcg_iters, dtype=np.int64)
-        # Final-step PCG work counts, matching the unbatched accounting.
-        matvecs += np.where(active, pcg, 0)
-        if donate:
-            # The device already applied the freeze mask to v_new; mirror its
-            # decision so host bookkeeping and the update cannot diverge.
-            advance = np.asarray(adv_dev, dtype=bool) & active
-            just_conv = active & ~advance
-            v = stats.v_new
-        else:
-            just_conv = active & (rel <= gn.tol_rel_grad)
-            advance = active & ~just_conv
-            mask = jnp.asarray(advance).reshape((bsz,) + (1,) * (v.ndim - 1))
-            v = jnp.where(mask, stats.v_new, v)
-        ever_converged |= just_conv
-        iters += advance
-        eta = np.where(
-            advance,
-            np.minimum(gn.forcing_max,
-                       np.sqrt(np.maximum(gnorm, 0.0) / np.maximum(gnorm0, 1e-30))),
-            eta,
-        )
-        history.append(
-            dict(
-                gnorm=gnorm,
-                rel_grad=rel,
-                active=active.copy(),
-                j=np.asarray(stats.j_total, dtype=np.float64),
-                j_mismatch=np.asarray(stats.j_mismatch, dtype=np.float64),
-                pcg_iters=pcg,
-                alpha=np.asarray(stats.alpha, dtype=np.float64),
-            )
-        )
-        if verbose:
-            print(
-                f"[GN-batch] it={len(history) - 1:3d} active={int(active.sum())} "
-                f"|g|rel={np.array2string(rel, precision=3)} pcg={pcg}"
-            )
-        active = advance
-        if not active.any():
-            break
+            history.append(dict(gnorm=gnorm, rel_grad=rel, active=active.copy(), **h))
+            if verbose:
+                print(
+                    f"[GN-batch] it={len(history) - 1:3d} active={int(active.sum())} "
+                    f"|g|rel={np.array2string(rel, precision=3)} pcg={pcg}"
+                )
+            active = advance
+            if not active.any():
+                break
 
     rel_final = np.where(gnorm0 > 0, gnorm_last / gnorm0, 0.0) if gnorm0 is not None \
         else np.zeros(bsz)
@@ -465,5 +488,5 @@ def solve_batch(
         rel_grad=rel_final,
         converged=ever_converged | (rel_final <= gn.tol_rel_grad),
         history=history,
-        wall_time_s=time.perf_counter() - t0,
+        wall_time_s=obs.elapsed_s(first, last),
     )

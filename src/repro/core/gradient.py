@@ -11,6 +11,8 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import derivatives as _deriv
 from . import measures as _meas
 from . import spectral as _spec
@@ -42,6 +44,7 @@ class GradientState(NamedTuple):
     measure_cache: object = None  # per-measure terminal cache (measures.py)
 
 
+@obs.scoped(obs.GRADIENT)
 def evaluate(
     m0: jnp.ndarray,
     m1: jnp.ndarray,

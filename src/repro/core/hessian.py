@@ -37,6 +37,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import gradient as _grad
 from . import interp as _interp
 from . import measures as _meas
@@ -54,6 +56,7 @@ def _fused_coefficients(stack: jnp.ndarray, cfg: _tr.TransportConfig):
     return _interp.prefilter_for(stack, cfg.interp)
 
 
+@obs.scoped(obs.MATVEC)
 def _matvec_fused(
     vt: jnp.ndarray,
     gs: _grad.GradientState,
@@ -110,6 +113,7 @@ def _matvec_fused(
     return _spec.apply_regop(vt, beta, gamma, shard=cfg.shard) + body
 
 
+@obs.scoped(obs.MATVEC)
 def matvec(
     vt: jnp.ndarray,
     gs: _grad.GradientState,

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 # ---------------------------------------------------------------------------
 # B-spline prefilter
 # ---------------------------------------------------------------------------
@@ -206,6 +208,7 @@ def interp_vector(w: jnp.ndarray, q: jnp.ndarray, method: str = "cubic_bspline",
     return apply_plan(plan, coef)
 
 
+@obs.scoped(obs.INTERP_PREFILTER)
 def prefilter_for(f: jnp.ndarray, method: str) -> jnp.ndarray:
     """Return interpolation coefficients for ``method`` (identity unless
     B-spline). Leading batch axes are filtered in the same traced pass."""
@@ -262,6 +265,7 @@ class InterpPlan:
         return cls(idx, weights, *aux)
 
 
+@obs.scoped(obs.INTERP_PLAN)
 def build_plan(q: jnp.ndarray, method: str = "cubic_bspline",
                weight_dtype=None, shape=None,
                wrap=(True, True, True)) -> InterpPlan:
@@ -304,6 +308,7 @@ def build_plan(q: jnp.ndarray, method: str = "cubic_bspline",
     return InterpPlan((idx1, idx2, idx3), (w1, w2, w3), method, shape)
 
 
+@obs.scoped(obs.INTERP_APPLY)
 def apply_plan(plan: InterpPlan, coef: jnp.ndarray) -> jnp.ndarray:
     """Evaluate interpolation ``coef`` through a prebuilt plan (fp32 accum).
 

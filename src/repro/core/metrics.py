@@ -17,6 +17,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import derivatives as _deriv
 from . import grid as _grid
 from . import interp as _interp
@@ -63,11 +65,13 @@ def det_deformation_gradient(
     )
 
 
+@obs.scoped(obs.SCORE)
 def detF_stats(v: jnp.ndarray, cfg: _tr.TransportConfig) -> Dict[str, jnp.ndarray]:
     d = det_deformation_gradient(v, cfg)
     return dict(min=jnp.min(d), mean=jnp.mean(d), max=jnp.max(d))
 
 
+@obs.scoped(obs.SCORE)
 def warp_image(
     m0: jnp.ndarray, v: jnp.ndarray, cfg: _tr.TransportConfig
 ) -> jnp.ndarray:
@@ -75,6 +79,7 @@ def warp_image(
     return _tr.solve_state(m0, v, cfg)[-1]
 
 
+@obs.scoped(obs.SCORE)
 def warp_labels(
     labels: jnp.ndarray, v: jnp.ndarray, cfg: _tr.TransportConfig
 ) -> jnp.ndarray:
@@ -89,6 +94,7 @@ def warp_labels(
     return (warped >= 0.5).astype(labels.dtype)
 
 
+@obs.scoped(obs.SCORE)
 def dice(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Dice overlap of two binary masks."""
     a = a.astype(jnp.float32)

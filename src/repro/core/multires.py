@@ -32,10 +32,11 @@ preserve ``cfg.measure``.
 
 from __future__ import annotations
 
-import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+
+from repro import obs
 
 from . import gauss_newton as _gn
 from . import spectral as _spec
@@ -170,7 +171,8 @@ class MultiresResult(NamedTuple):
     rel_grad: float                 # final relative gradient (finest level)
     converged: bool
     history: List[Dict[str, float]]  # per-iteration records tagged with shape
-    wall_time_s: float
+    wall_time_s: float              # first level's start to last level's end
+    #                                 (claire.level spans), compiling included
 
 
 def solve_multires(
@@ -250,56 +252,58 @@ def solve_multires(
     total_iters = 0
     total_matvecs = 0
     last: _gn.GNResult | None = None
-    t0 = time.perf_counter()
+    first_span = last_span = None
 
     for li, lev in enumerate(levels):
-        is_finest = li == len(levels) - 1
-        if is_finest:
-            m0_l, m1_l = m0, m1
-        else:
-            m0_l, m1_l = restrict(m0_s, lev), restrict(m1_s, lev)
-        cfg_l = level_cfgs[li] if level_cfgs is not None else cfg
-        tol_l = gn.tol_rel_grad if (is_finest or coarse_tol is None) else coarse_tol
-        gn_l = gn._replace(
-            tol_rel_grad=tol_l,
-            max_newton=int(level_newton[li]) if level_newton is not None else gn.max_newton,
-            continuation=gn.continuation and li == 0,
-        )
-        if v is not None:
-            v0_l = prolong(v, lev)
-        elif v0 is not None:
-            # Caller-provided start (finest-grid field): restrict onto the
-            # coarsest level instead of silently dropping it.
-            v0_l = fourier_resample(v0, lev)
-        else:
-            v0_l = None
-        # First-step PCG forcing at warm levels: the coarse level's final
-        # relative gradient is the best available Eisenstat-Walker estimate.
-        eta0 = None
-        if level_results:
-            eta0 = min(gn.forcing_max, level_results[-1].rel_grad ** 0.5)
-        if verbose:
-            print(f"[multires] level {li}: {lev} (warm={'yes' if v0_l is not None else 'no'})")
-        _solve = solve_fn if solve_fn is not None else _gn.solve
-        res = _solve(m0_l, m1_l, cfg_l, gn_l, v0=v0_l, gnorm_ref=gnorm_ref,
-                     eta0=eta0, verbose=verbose)
-        if gnorm_ref is None and res.gnorm0 > 0:
-            gnorm_ref = res.gnorm0
-        v = res.v
-        last = res
-        total_iters += res.iters
-        total_matvecs += res.matvecs
-        level_results.append(
-            LevelResult(
-                shape=lev,
-                iters=res.iters,
-                matvecs=res.matvecs,
-                rel_grad=res.rel_grad,
-                converged=res.converged,
-                wall_time_s=res.wall_time_s,
+        with obs.span(obs.LEVEL, level=li, grid=lev) as last_span:
+            first_span = first_span or last_span
+            is_finest = li == len(levels) - 1
+            if is_finest:
+                m0_l, m1_l = m0, m1
+            else:
+                m0_l, m1_l = restrict(m0_s, lev), restrict(m1_s, lev)
+            cfg_l = level_cfgs[li] if level_cfgs is not None else cfg
+            tol_l = gn.tol_rel_grad if (is_finest or coarse_tol is None) else coarse_tol
+            gn_l = gn._replace(
+                tol_rel_grad=tol_l,
+                max_newton=int(level_newton[li]) if level_newton is not None else gn.max_newton,
+                continuation=gn.continuation and li == 0,
             )
-        )
-        history.extend(dict(h, grid=lev) for h in res.history)
+            if v is not None:
+                v0_l = prolong(v, lev)
+            elif v0 is not None:
+                # Caller-provided start (finest-grid field): restrict onto the
+                # coarsest level instead of silently dropping it.
+                v0_l = fourier_resample(v0, lev)
+            else:
+                v0_l = None
+            # First-step PCG forcing at warm levels: the coarse level's final
+            # relative gradient is the best available Eisenstat-Walker estimate.
+            eta0 = None
+            if level_results:
+                eta0 = min(gn.forcing_max, level_results[-1].rel_grad ** 0.5)
+            if verbose:
+                print(f"[multires] level {li}: {lev} (warm={'yes' if v0_l is not None else 'no'})")
+            _solve = solve_fn if solve_fn is not None else _gn.solve
+            res = _solve(m0_l, m1_l, cfg_l, gn_l, v0=v0_l, gnorm_ref=gnorm_ref,
+                         eta0=eta0, verbose=verbose)
+            if gnorm_ref is None and res.gnorm0 > 0:
+                gnorm_ref = res.gnorm0
+            v = res.v
+            last = res
+            total_iters += res.iters
+            total_matvecs += res.matvecs
+            level_results.append(
+                LevelResult(
+                    shape=lev,
+                    iters=res.iters,
+                    matvecs=res.matvecs,
+                    rel_grad=res.rel_grad,
+                    converged=res.converged,
+                    wall_time_s=res.wall_time_s,
+                )
+            )
+            history.extend(dict(h, grid=lev) for h in res.history)
 
     return MultiresResult(
         v=v,
@@ -311,5 +315,5 @@ def solve_multires(
         rel_grad=last.rel_grad if last is not None else 0.0,
         converged=last.converged if last is not None else False,
         history=history,
-        wall_time_s=time.perf_counter() - t0,
+        wall_time_s=obs.elapsed_s(first_span, last_span),
     )

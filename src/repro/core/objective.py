@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import grid as _grid
 from . import measures as _meas
 from . import spectral as _spec
@@ -21,6 +23,7 @@ def mismatch(m_final: jnp.ndarray, m1: jnp.ndarray, shard=None) -> jnp.ndarray:
     return 0.5 * _grid.inner(r, r, shard=shard)
 
 
+@obs.scoped(obs.SCORE)
 def relative_mismatch(m_final: jnp.ndarray, m1: jnp.ndarray, m0: jnp.ndarray) -> jnp.ndarray:
     """The paper's reported metric: ||m(.,1)-m1||_2 / ||m1 - m0||_2.
 

@@ -17,6 +17,8 @@ from typing import Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import grid as _grid
 from . import spectral as _spec
 
@@ -27,6 +29,7 @@ class PCGResult(NamedTuple):
     rel_residual: jnp.ndarray
 
 
+@obs.scoped(obs.PCG)
 def solve(
     matvec: Callable[[jnp.ndarray], jnp.ndarray],
     b: jnp.ndarray,
@@ -83,6 +86,7 @@ def make_reg_preconditioner(beta: float, gamma: float,
                             shard=None) -> Callable[[jnp.ndarray], jnp.ndarray]:
     """(beta*A)^-1 spectral preconditioner (Algorithm 2.1 'Preconditioner')."""
 
+    @obs.scoped(obs.PRECOND)
     def precond(r: jnp.ndarray) -> jnp.ndarray:
         return _spec.apply_inv_regop(r, beta, gamma, zero_mean_identity=True,
                                      shard=shard)

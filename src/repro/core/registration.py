@@ -18,6 +18,8 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import gauss_newton as _gn
 from . import measures as _meas
 from . import metrics as _metrics
@@ -47,6 +49,7 @@ def _unshard(v, mesh):
     return jax.device_put(v, NamedSharding(mesh, PartitionSpec()))
 
 
+@obs.span(obs.SCORE)
 def _score_single(m0, m1, v, cfg):
     """Post-solve quality metrics (warped image, rel. mismatch, det F)."""
     m_warped = _metrics.warp_image(m0, v, cfg)
@@ -55,6 +58,7 @@ def _score_single(m0, m1, v, cfg):
     return m_warped, mis, detf
 
 
+@obs.span(obs.SCORE)
 def _score_batch(m0, m1, v, cfg):
     """Batched post-solve scoring: one dispatch for all pairs."""
     bsz = m0.shape[0]
@@ -82,6 +86,7 @@ class RegistrationResult(NamedTuple):
     history: list
 
 
+@obs.span(obs.BUILD)
 def make_transport_config(
     variant: str = "fd8-cubic",
     nt: int = 4,

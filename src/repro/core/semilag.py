@@ -14,6 +14,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import grid as _grid
 from . import interp as _interp
 
@@ -30,6 +32,7 @@ _METHOD_TO_BASIS = {
 }
 
 
+@obs.scoped(obs.INTERP_PREFILTER)
 def _prefilter_dispatch(f, method, backend):
     """Interpolation coefficients for ``method`` (B-spline prefilter or id).
 
@@ -50,6 +53,7 @@ def _prefilter_dispatch(f, method, backend):
     return _interp.prefilter_for(f, method)
 
 
+@obs.scoped(obs.INTERP_APPLY)
 def _interp_dispatch(coef, q, method, weight_dtype, backend):
     """Interpolate prefiltered coefficients at q via XLA or Pallas kernel."""
     if backend == "pallas":
@@ -64,6 +68,7 @@ def _interp_dispatch(coef, q, method, weight_dtype, backend):
                                 weight_dtype=weight_dtype)
 
 
+@obs.scoped(obs.INTERP_PLAN)
 def build_plan(foot: jnp.ndarray, method: str, weight_dtype=None,
                shape=None) -> _interp.InterpPlan:
     """Precompute the interpolation plan for footpoints ``foot``.
@@ -77,6 +82,7 @@ def build_plan(foot: jnp.ndarray, method: str, weight_dtype=None,
                               shape=shape)
 
 
+@obs.scoped(obs.INTERP_APPLY)
 def _apply_plan_dispatch(plan, coef, backend):
     """Apply a prebuilt plan to (stacked) coefficients via XLA or Pallas."""
     if backend == "pallas":

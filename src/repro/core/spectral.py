@@ -24,6 +24,8 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import grid as _grid
 
 
@@ -57,6 +59,7 @@ def _vec_irfftn(vh: jnp.ndarray, shape, dtype):
     )
 
 
+@obs.scoped(obs.SPECTRAL)
 def apply_regop(v: jnp.ndarray, beta: float, gamma: float, shard=None) -> jnp.ndarray:
     """A v = beta*(-Lap) v + gamma * k (k . vhat)  (vector field -> vector field).
 
@@ -76,6 +79,7 @@ def apply_regop(v: jnp.ndarray, beta: float, gamma: float, shard=None) -> jnp.nd
     return _vec_irfftn(out, shape, v.dtype)
 
 
+@obs.scoped(obs.SPECTRAL)
 def apply_inv_regop(
     v: jnp.ndarray, beta: float, gamma: float, zero_mean_identity: bool = True,
     shard=None
@@ -110,6 +114,7 @@ def apply_inv_regop(
     return _vec_irfftn(jnp.stack(outs, axis=0), shape, v.dtype)
 
 
+@obs.scoped(obs.SPECTRAL)
 def leray_project(v: jnp.ndarray) -> jnp.ndarray:
     """Leray projection onto divergence-free fields:
     P v = v - grad Lap^-1 div v   <=>   vhat - k (k.vhat) / |k|^2.
@@ -123,6 +128,7 @@ def leray_project(v: jnp.ndarray) -> jnp.ndarray:
     return _vec_irfftn(out, shape, v.dtype)
 
 
+@obs.scoped(obs.SPECTRAL)
 def reg_energy(v: jnp.ndarray, beta: float, gamma: float, shard=None) -> jnp.ndarray:
     """0.5 * <A v, v>  =  0.5*beta*|grad v|^2 + 0.5*gamma*|div v|^2 (spectral).
 

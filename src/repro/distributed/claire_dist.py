@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import gauss_newton as _gn
 from repro.core import gradient as _grad
 from repro.core import grid as _grid
@@ -201,6 +202,7 @@ def _check_slab_cfg(cfg: _tr.TransportConfig):
             f"{cfg.backend!r}")
 
 
+@obs.span(obs.BUILD)
 def make_slab_step(mesh: Mesh, cfg: _tr.TransportConfig, gn: _gn.GNConfig,
                    slab_axis: Optional[str] = None, halo: int = 6,
                    ens_axis: Optional[str] = None, compress: str = "none"):
@@ -243,7 +245,12 @@ def make_slab_step(mesh: Mesh, cfg: _tr.TransportConfig, gn: _gn.GNConfig,
     fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(img, img, vel, P(), P(), eta_spec),
                        out_specs=out_specs, check_vma=False)
-    return jax.jit(fn)
+
+    def step(m0, m1, v, beta, gamma, eta):
+        obs.count_trace("newton_step_slab")
+        return fn(m0, m1, v, beta, gamma, eta)
+
+    return jax.jit(step)
 
 
 def _validate_slab(shape, mesh: Mesh, slab_axis: str, halo: int):

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core import grid as _grid
 from repro.core import interp as _interp
 from repro.core.derivatives import FD8_COEFFS
@@ -291,6 +292,7 @@ def _prefilter_local(f: jnp.ndarray, method: str, shard: ShardInfo) -> jnp.ndarr
     return _interp.prefilter_for(f, method)
 
 
+@obs.scoped(obs.INTERP_APPLY)
 def _apply_plan_local(plan: _interp.InterpPlan, coef: jnp.ndarray,
                       shard: ShardInfo) -> jnp.ndarray:
     """Plan gather on the halo-extended coefficient slab (Pallas or XLA)."""
@@ -301,6 +303,7 @@ def _apply_plan_local(plan: _interp.InterpPlan, coef: jnp.ndarray,
     return _interp.apply_plan(plan, coef)
 
 
+@obs.scoped(obs.INTERP_PLAN)
 def build_plan(foot: jnp.ndarray, method: str, weight_dtype, shard: ShardInfo
                ) -> _interp.InterpPlan:
     """Interpolation plan for *global-coordinate* footpoints of a local slab.
@@ -320,6 +323,7 @@ def build_plan(foot: jnp.ndarray, method: str, weight_dtype, shard: ShardInfo
                               shape=ext_shape, wrap=(False, True, True))
 
 
+@obs.scoped(obs.INTERP_PREFILTER)
 def sl_coefficients(f: jnp.ndarray, method: str, shard: ShardInfo) -> jnp.ndarray:
     """Halo-extended interpolation coefficients for local field(s) ``f``.
 

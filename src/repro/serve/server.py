@@ -44,6 +44,7 @@ from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import gauss_newton as _gn
 from repro.core import metrics as _metrics
 from repro.core import registration as _reg
@@ -319,6 +320,7 @@ class Server:
             cfg_t = self._transport_cfg(key)
 
             def score(m0b, m1b, vb):
+                obs.count_trace("serve_scorer")
                 warped = jax.vmap(
                     lambda m, w: _metrics.warp_image(m, w, cfg_t))(m0b, vb)
                 num = jnp.sqrt(jnp.sum((warped - m1b) ** 2, axis=(1, 2, 3)))
@@ -338,26 +340,30 @@ class Server:
                 return
             wave: _AssembledWave = item
             try:
-                cfg_t = self._transport_cfg(wave.key)
-                step = self._step_for(wave.key)
-                t0 = time.perf_counter()
-                if c.mesh is not None:
-                    from repro.distributed import claire_dist as _dist
-                    res = _dist.solve_ensemble_slab(
-                        wave.m0, wave.m1, cfg_t, self._gn, mesh=c.mesh,
-                        ens_axis=self._ens_axis, slab_axis=self._slab_axis,
-                        halo=c.halo, v0=wave.v0, gnorm_ref=wave.gnorm_ref,
-                        step_fn=step)
-                    v_host = _reg._unshard(res.v, c.mesh)
-                else:
-                    res = _gn.solve_batch(
-                        wave.m0, wave.m1, cfg_t, self._gn, v0=wave.v0,
-                        gnorm_ref=wave.gnorm_ref, step_fn=step, donate=True)
-                    v_host = res.v
-                # Dispatch scoring asynchronously; the collector forces it
-                # while the solver starts the next wave.
-                mismatch = self._scorer_for(wave.key)(wave.m0, wave.m1, v_host)
-                solve_s = time.perf_counter() - t0
+                with obs.span(obs.SOLVE, mode="serve",
+                              grid=tuple(wave.m0.shape[1:]),
+                              batch=int(wave.m0.shape[0]),
+                              sharded=c.mesh is not None):
+                    cfg_t = self._transport_cfg(wave.key)
+                    step = self._step_for(wave.key)
+                    t0 = time.perf_counter()
+                    if c.mesh is not None:
+                        from repro.distributed import claire_dist as _dist
+                        res = _dist.solve_ensemble_slab(
+                            wave.m0, wave.m1, cfg_t, self._gn, mesh=c.mesh,
+                            ens_axis=self._ens_axis, slab_axis=self._slab_axis,
+                            halo=c.halo, v0=wave.v0, gnorm_ref=wave.gnorm_ref,
+                            step_fn=step)
+                        v_host = _reg._unshard(res.v, c.mesh)
+                    else:
+                        res = _gn.solve_batch(
+                            wave.m0, wave.m1, cfg_t, self._gn, v0=wave.v0,
+                            gnorm_ref=wave.gnorm_ref, step_fn=step, donate=True)
+                        v_host = res.v
+                    # Dispatch scoring asynchronously; the collector forces it
+                    # while the solver starts the next wave.
+                    mismatch = self._scorer_for(wave.key)(wave.m0, wave.m1, v_host)
+                    solve_s = time.perf_counter() - t0
             except Exception as e:
                 for p in wave.pendings:
                     p.future.set_exception(e)
