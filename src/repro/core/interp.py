@@ -8,8 +8,12 @@ This mirrors the paper's interpolation kernel family:
                                         15-point finite convolution of the paper)
 
 GPU texture hardware does not exist on TPU; this module is the XLA-gather
-implementation (used by tests as oracle and by the distributed path). The
-Pallas halo-tile kernels live in ``repro.kernels.interp3d``.
+implementation. The plan path (:func:`build_plan` / :func:`apply_plan`)
+fetches each query point's whole tap window -- ``support**3`` taps of every
+stacked field -- with one ``lax.gather`` of one row of a tap block, built
+per application from the padded coefficient block. The plan-free
+:func:`interp_field` gathers one scalar per tap and is the tests' oracle.
+The Pallas halo-tile kernels live in ``repro.kernels.interp3d``.
 
 Query points ``q`` have shape (3, *out_shape) and are measured in *index
 units* (physical coordinate / h). Periodic wrap is applied.
@@ -24,6 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 
@@ -223,9 +228,16 @@ def prefilter_for(f: jnp.ndarray, method: str) -> jnp.ndarray:
 # For a stationary velocity the SL footpoints — and therefore the gather
 # indices and basis weights — are identical for every transport step and
 # every PCG Hessian matvec inside one Newton step (the paper's Table 1
-# accounting). A plan precomputes the flattened periodic gather bases and
-# the per-axis weight stacks so each application is a pure
-# gather-multiply-accumulate.
+# accounting). A plan precomputes each point's tap-window start and the
+# per-axis weight stacks so each application is one gather of every point's
+# window and a multiply-accumulate over it.
+#
+# Padding rule: the coefficient block is padded by ``support - 1`` so that
+# every window lies inside it. A periodic axis is padded on the high side
+# with wrapped values and its start is ``base mod N``. A clamped axis is
+# edge-padded on both sides and its start is ``clip(base, 1 - support, N - 1)
+# + support - 1``; the window then holds ``clip(base + tap, 0, N - 1)`` for
+# every base, as the plan's ``idx`` does.
 # ---------------------------------------------------------------------------
 
 
@@ -233,20 +245,26 @@ def prefilter_for(f: jnp.ndarray, method: str) -> jnp.ndarray:
 class InterpPlan:
     """Precomputed tensor-product interpolation plan.
 
+    start   : int32 array (3, *out_shape) — per point and axis, the start of
+              the point's tap window in the padded coefficient block that
+              :func:`apply_plan` gathers from (see the padding rule above).
     idx     : 3-tuple of int32 arrays (support, *out_shape) — per-axis flat
-              index contributions, periodic wrap and row strides baked in
-              (idx[0] premultiplied by N2*N3, idx[1] by N3).
+              index contributions, periodic wrap (or clamp) and row strides
+              baked in (idx[0] premultiplied by N2*N3, idx[1] by N3); read by
+              the Pallas kernels of ``repro.kernels.interp3d``.
     weights : 3-tuple of arrays (support, *out_shape) — per-axis basis
               weights, optionally downcast (bf16 mixed-precision path).
-    method / field_shape are static metadata (pytree aux), so plans pass
-    through jit/scan/vmap with the basis baked into the trace.
+    method / field_shape / wrap are static metadata (pytree aux), so plans
+    pass through jit/scan/vmap with the basis baked into the trace.
     """
 
-    def __init__(self, idx, weights, method, field_shape):
+    def __init__(self, start, idx, weights, method, field_shape, wrap):
+        self.start = start
         self.idx = tuple(idx)
         self.weights = tuple(weights)
         self.method = method
         self.field_shape = tuple(field_shape)
+        self.wrap = tuple(bool(w) for w in wrap)
 
     @property
     def support(self) -> int:
@@ -257,12 +275,12 @@ class InterpPlan:
         return self.idx[0].shape[1:]
 
     def tree_flatten(self):
-        return (self.idx, self.weights), (self.method, self.field_shape)
+        return ((self.start, self.idx, self.weights),
+                (self.method, self.field_shape, self.wrap))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        idx, weights = children
-        return cls(idx, weights, *aux)
+        return cls(*children, *aux)
 
 
 @obs.scoped(obs.INTERP_PLAN)
@@ -278,7 +296,9 @@ def build_plan(q: jnp.ndarray, method: str = "cubic_bspline",
     ``wrap`` selects per-axis periodic index wrap; a non-wrapped axis clamps
     tap indices into the field instead — used by the distributed halo path,
     where the x1 axis of the source is a halo-extended (non-periodic) slab
-    and the CFL contract keeps in-range queries exact.
+    and the CFL contract keeps in-range queries exact. The window starts
+    follow the padding rule above, so :func:`apply_plan` reads the same taps
+    as ``idx`` names.
     """
     if method not in _METHOD_TABLE:
         raise ValueError(f"unknown interpolation method: {method}")
@@ -295,6 +315,12 @@ def build_plan(q: jnp.ndarray, method: str = "cubic_bspline",
         i = b[None] + tap
         return jnp.mod(i, n) if do_wrap else jnp.clip(i, 0, n - 1)
 
+    def _start(b, n, do_wrap):
+        if do_wrap:
+            return jnp.mod(b, n)
+        return jnp.clip(b, 1 - support, n - 1) + (support - 1)
+
+    start = jnp.stack([_start(base[k], shape[k], wrap[k]) for k in range(3)])
     idx1 = _tap_idx(base[0], n1, wrap[0]) * (n2 * n3)
     idx2 = _tap_idx(base[1], n2, wrap[1]) * n3
     idx3 = _tap_idx(base[2], n3, wrap[2])
@@ -305,7 +331,43 @@ def build_plan(q: jnp.ndarray, method: str = "cubic_bspline",
         w1 = w1.astype(weight_dtype)
         w2 = w2.astype(weight_dtype)
         w3 = w3.astype(weight_dtype)
-    return InterpPlan((idx1, idx2, idx3), (w1, w2, w3), method, shape)
+    return InterpPlan(start, (idx1, idx2, idx3), (w1, w2, w3), method, shape,
+                      wrap)
+
+
+def _padded_block(coef: jnp.ndarray, support: int, wrap) -> jnp.ndarray:
+    """``(L, N1, N2, N3)`` padded on its three grid axes by the padding rule,
+    so that every plan window of ``support**3`` taps lies inside."""
+    block = coef
+    for axis, periodic in enumerate(wrap, start=1):
+        width = [(0, 0)] * 4
+        if periodic:
+            width[axis] = (0, support - 1)
+            block = jnp.pad(block, width, mode="wrap")
+        else:
+            width[axis] = (support - 1, support - 1)
+            block = jnp.pad(block, width, mode="edge")
+    return block
+
+
+def _tap_block(coef: jnp.ndarray, support: int, wrap) -> jnp.ndarray:
+    """``(L, N1, N2, N3)`` -> ``(M1, M2, M3, L * support**3)``, the tap block.
+
+    Row ``(i, j, k)`` holds the whole window of the padded block that starts
+    there: for each field, taps ``(a, b, c)`` in row-major order. A plan's
+    window start then names one row, and one row gather per point fetches
+    every tap of every field. The taps are sliced from the field-major
+    padded block and moved to the minor axis only when stacked, so no
+    intermediate carries a minor axis of size ``L`` (on the TPU such an axis
+    is padded to a full tile).
+    """
+    block = _padded_block(coef, support, wrap)
+    n_fields = block.shape[0]
+    m1, m2, m3 = (n - support + 1 for n in block.shape[1:])
+    taps = jnp.stack([block[:, a:a + m1, b:b + m2, c:c + m3]
+                      for a in range(support) for b in range(support)
+                      for c in range(support)], axis=-1)
+    return jnp.moveaxis(taps, 0, 3).reshape(m1, m2, m3, n_fields * support**3)
 
 
 @obs.scoped(obs.INTERP_APPLY)
@@ -313,24 +375,34 @@ def apply_plan(plan: InterpPlan, coef: jnp.ndarray) -> jnp.ndarray:
     """Evaluate interpolation ``coef`` through a prebuilt plan (fp32 accum).
 
     ``coef`` may carry arbitrary leading batch axes (``(..., N1, N2, N3)``);
-    all stacked fields are gathered through the same plan in one pass.
-    Returns ``coef.shape[:-3] + plan.out_shape`` in float32.
+    they are flattened to ``L`` fields and laid out as the tap block of
+    :func:`_tap_block`, and one ``lax.gather`` fetches each point's row of
+    ``support**3 * L`` values: every tap of every field. The window is then
+    contracted with ``w1 ⊗ w2 ⊗ w3`` as the same ``support**3`` float32
+    products, summed in the same order as :func:`interp_field`. Returns
+    ``coef.shape[:-3] + plan.out_shape`` in float32.
     """
     if tuple(coef.shape[-3:]) != plan.field_shape:
         raise ValueError(
             f"field shape {coef.shape[-3:]} != plan field shape {plan.field_shape}")
     support = plan.support
-    i1, i2, i3 = plan.idx
-    w1, w2, w3 = plan.weights
     lead = coef.shape[:-3]
-    f_flat = coef.reshape(lead + (-1,))
-    acc = jnp.zeros(lead + tuple(plan.out_shape), dtype=jnp.float32)
+    out_shape = tuple(plan.out_shape)
+    n_fields = math.prod(lead)
+    block = _tap_block(coef.reshape((n_fields,) + plan.field_shape),
+                       support, plan.wrap)
+    dnums = lax.GatherDimensionNumbers(offset_dims=(0,),
+                                       collapsed_slice_dims=(0, 1, 2),
+                                       start_index_map=(0, 1, 2))
+    window = lax.gather(block, jnp.moveaxis(plan.start, 0, -1), dnums,
+                        (1, 1, 1, block.shape[-1]),
+                        mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    window = window.reshape((n_fields,) + (support,) * 3 + out_shape)
+    w1, w2, w3 = plan.weights
+    acc = jnp.zeros((n_fields,) + out_shape, dtype=jnp.float32)
     for a in range(support):
-        ia = i1[a]
         for b in range(support):
-            iab = ia + i2[b]
             wab = w1[a] * w2[b]
             for c in range(support):
-                vals = jnp.take(f_flat, iab + i3[c], axis=-1)
-                acc = acc + (wab * w3[c] * vals).astype(jnp.float32)
-    return acc
+                acc = acc + (wab * w3[c] * window[:, a, b, c]).astype(jnp.float32)
+    return acc.reshape(lead + out_shape)
